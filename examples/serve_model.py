@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from repro.configs.registry import get_config, reduced_config
+from repro.launch.mesh import use_compile_cache
 from repro.models import build_model
 from repro.serving.engine import ServeRequest, ServingEngine
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = reduced_config(get_config(args.arch))
     bundle = build_model(cfg)
     params = bundle.init(jax.random.key(0))

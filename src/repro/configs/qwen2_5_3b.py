@@ -1,4 +1,4 @@
-"""qwen2.5-3b — dense, GQA kv=2, QKV bias. [hf:Qwen/Qwen2.5-0.5B family; hf]"""
+"""qwen2.5-3b — dense, GQA kv=2, QKV bias. [hf:Qwen/Qwen2.5-3B config.json]"""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -14,5 +14,5 @@ CONFIG = ArchConfig(
     qkv_bias=True,
     rope_theta=1_000_000.0,
     tie_embeddings=True,
-    source="hf:Qwen/Qwen2.5-0.5B; hf",
+    source="hf:Qwen/Qwen2.5-3B config.json",
 )

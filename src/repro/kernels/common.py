@@ -9,19 +9,6 @@ def use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tpu_compiler_params(**kwargs):
-    """Pallas TPU compiler params across JAX versions.
-
-    Newer JAX exposes ``pltpu.CompilerParams``; older releases call the
-    same dataclass ``TPUCompilerParams``.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
 def pad_to(x, multiple: int, axis: int):
     """Zero-pad ``axis`` of x up to a multiple; returns (padded, orig_len)."""
     import jax.numpy as jnp

@@ -1,7 +1,5 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# TPU-faithful bf16 dots in the compiled HLO (never executed here):
-os.environ["REPRO_EXEC_SAFE"] = "0"
 
 """Multi-pod dry-run (deliverable e).
 
@@ -94,15 +92,8 @@ def pick_microbatches(cfg: ArchConfig, shape: InputShape, mesh) -> int:
 
 @contextlib.contextmanager
 def _mesh_context(mesh):
-    """Enter the mesh — and, where the installed JAX has it, the
-    abstract-mesh context that newer shard_hint paths read.  Older JAX
-    (no ``use_abstract_mesh``) exposes the physical mesh to tracing via
-    the pxla thread-resources env, which shard_hint falls back to."""
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(mesh)
-        use_am = getattr(jax.sharding, "use_abstract_mesh", None)
-        if use_am is not None:
-            stack.enter_context(use_am(mesh.abstract_mesh))
+    """Enter the mesh and its abstract mesh, which ``shard_hint`` reads."""
+    with mesh, jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         yield
 
 
@@ -179,9 +170,6 @@ def lower_cell(cfg: ArchConfig, shape: InputShape, mesh, mesh_name: str):
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    # older JAX returns one dict per device program in a list
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled)
     from repro.analysis.hlo_stats import analyze_compiled
     hlo = analyze_compiled(compiled)

@@ -31,20 +31,12 @@ from jax.sharding import PartitionSpec as P
 DEFAULT_DTYPE = jnp.bfloat16
 ACC_DTYPE = jnp.float32
 
-# The CPU backend's batched DotThunk cannot *execute* bf16 x bf16 -> f32
-# dots (compilation is fine).  Anything that actually runs on this
-# container (smoke tests, the serving engine, examples) therefore upcasts
-# to f32 before accumulating dots; the dry-run — which only lowers and
-# compiles for the TPU-shaped mesh — sets REPRO_EXEC_SAFE=0 to keep
-# TPU-faithful bf16 dots with f32 accumulation in the compiled HLO.
-EXEC_SAFE = os.environ.get("REPRO_EXEC_SAFE", "1") == "1"
-
 
 def einsum_acc(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
-    """einsum with f32 accumulation, f32 output; CPU-executable."""
-    if EXEC_SAFE:
-        return jnp.einsum(spec, a.astype(ACC_DTYPE), b.astype(ACC_DTYPE))
+    """einsum of the operands as given (bf16 on the MXU), f32 accumulation
+    and f32 output."""
     return jnp.einsum(spec, a, b, preferred_element_type=ACC_DTYPE)
+
 
 # Mesh axis names used across the framework (see repro/launch/mesh.py).
 AXIS_POD = "pod"
@@ -54,29 +46,6 @@ AXIS_MODEL = "model"
 BATCH_AXES = (AXIS_POD, AXIS_DATA)
 
 
-def _ambient_mesh_axis_names() -> set:
-    """Axis names of the ambient mesh, across JAX versions.
-
-    ``jax.sharding.get_abstract_mesh`` only exists in newer JAX; older
-    releases expose the ambient mesh via the pxla thread-resources env.
-    Outside any mesh context (or if neither API exists) returns the empty
-    set, making :func:`shard_hint` a no-op hint.
-    """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        mesh = get_am()
-        return set(getattr(mesh, "axis_names", ()) or ())
-    try:
-        from jax.interpreters import pxla
-
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return set(mesh.axis_names)
-    except (ImportError, AttributeError):
-        pass
-    return set()
-
-
 def shard_hint(x: jax.Array, *entries) -> jax.Array:
     """with_sharding_constraint against whatever mesh axes exist.
 
@@ -84,7 +53,7 @@ def shard_hint(x: jax.Array, *entries) -> jax.Array:
     from the ambient mesh are dropped, and with no mesh this is a no-op —
     so model code can carry sharding hints without breaking CPU tests.
     """
-    names = _ambient_mesh_axis_names()
+    names = set(jax.sharding.get_abstract_mesh().axis_names)
     if not names:
         return x
 
@@ -200,11 +169,6 @@ BF16_ALLREDUCE = os.environ.get("REPRO_BF16_AR", "0") == "1"
 
 def matmul(x: jax.Array, w: jax.Array) -> jax.Array:
     """x @ w with f32 accumulation, output in x.dtype."""
-    if EXEC_SAFE:  # CPU DotThunk can't execute some bf16 dot shapes
-        out = jax.lax.dot_general(
-            x.astype(ACC_DTYPE), w.astype(ACC_DTYPE),
-            (((x.ndim - 1,), (0,)), ((), ())))
-        return out.astype(x.dtype)
     if BF16_ALLREDUCE and x.dtype == jnp.bfloat16:
         return jax.lax.dot_general(
             x, w, (((x.ndim - 1,), (0,)), ((), ())))
@@ -227,14 +191,9 @@ def lm_head_logits(x: jax.Array, table: jax.Array,
     vocab per device, which is a ~50 GiB/device blowup at V=256k.
     Padded vocab rows (table rows >= valid_vocab) are masked to -1e30.
     """
-    if EXEC_SAFE:
-        logits = jax.lax.dot_general(
-            x.astype(ACC_DTYPE), table.astype(ACC_DTYPE),
-            (((x.ndim - 1,), (1,)), ((), ())))
-    else:
-        logits = jax.lax.dot_general(
-            x, table, (((x.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=ACC_DTYPE)
+    logits = jax.lax.dot_general(
+        x, table, (((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=ACC_DTYPE)
     if valid_vocab is not None and valid_vocab < table.shape[0]:
         iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
                                         logits.ndim - 1)

@@ -83,16 +83,19 @@ def moe_block(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig) -> jax.Arr
     b_idx = jnp.arange(B)[:, None]
     buf = buf.at[b_idx, combined].add(x_rep)
     buf = shard_hint(buf, BATCH_AXES, None, None)
-    buf = buf.reshape(B, E, C + 1, d)
+    # expert-major: E is the batch dim of the grouped matmuls, and XLA's CPU
+    # backend executes a bf16 x bf16 -> f32 batched dot only with it leading
+    buf = buf.reshape(B, E, C + 1, d).transpose(1, 0, 2, 3)
 
     # --- grouped expert matmuls (d_ff TP-sharded over `model`) ---
-    up = einsum_acc("becd,edf->becf", buf, p["w_up"]).astype(x.dtype)
+    up = einsum_acc("ebcd,edf->ebcf", buf, p["w_up"]).astype(x.dtype)
     if "w_gate" in p:
-        gate = einsum_acc("becd,edf->becf", buf, p["w_gate"]).astype(x.dtype)
+        gate = einsum_acc("ebcd,edf->ebcf", buf, p["w_gate"]).astype(x.dtype)
         h = activate(gate, cfg.activation) * up
     else:
         h = activate(up, cfg.activation)
-    out_buf = einsum_acc("becf,efd->becd", h, p["w_down"]).astype(x.dtype)
+    out_buf = einsum_acc("ebcf,efd->ebcd", h, p["w_down"]).astype(x.dtype)
+    out_buf = out_buf.transpose(1, 0, 2, 3)
 
     # --- gather back + weighted combine over K ---
     out_flat = shard_hint(out_buf.reshape(B, E * (C + 1), d),

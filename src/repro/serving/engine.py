@@ -1,6 +1,9 @@
-"""A real JAX serving engine for the model zoo (executes on this host).
+"""The JAX serving engine: the on-chip path of this repository.
 
-Slot-based continuous batching on an actual :class:`ModelBundle`:
+``ServingEngine`` runs a :class:`ModelBundle` from ``repro.models`` on
+the default JAX device — a TPU chip in deployment (``chip_smoke.py`` serves
+Qwen2.5-3B at full width through it on one v5e), the CPU in tests.
+Slot-based continuous batching:
 
   * prefill admits a waiting request into a free slot (logits for its
     last token seed decoding); exact-prefix cache reuse via
@@ -12,10 +15,9 @@ Slot-based continuous batching on an actual :class:`ModelBundle`:
     ``attention_block_decode``);
   * greedy sampling; requests complete at EOS-budget exhaustion.
 
-This is the executable end-to-end serving driver (examples/serve_model.py
-batches requests through it).  The fleet-scale behavior is the discrete-
-event simulator; this engine proves the numerics and batching logic on
-real models.
+Everything Scepsy plans (trace, aggregate, profile, schedule, place) runs
+on the host and prices engines with ``serving/costmodel.py``; the fleet-
+scale behaviour is the discrete-event simulator.
 """
 from __future__ import annotations
 

@@ -46,64 +46,72 @@ class PrefixCache:
                 break  # only the just-inserted chain remains
 
     def longest_prefix(self, tokens: Sequence[int]) -> Tuple[int, Optional[int]]:
-        """Returns (matched_length, slot) of the deepest cached ancestor."""
+        """Returns (matched_length, slot) of the longest cached prefix.
+
+        Every leaf carries a slot (prefix-closed: a slotless leaf is pruned
+        or evicted), and a slot's KV covers every prefix of its sequence.
+        So the deepest node the prompt reaches is served by any slot found
+        below it, even where the prompt then diverges from every cached
+        sequence — the shared-system-prompt case.
+        """
         self.clock += 1
         node = self.root
-        best = (0, None)
         for t in tokens:
             nxt = node.children.get(t)
             if nxt is None:
                 break
             node = nxt
             node.stamp = self.clock
-            if node.slot is not None:
-                best = (node.depth, node.slot)
-        return best
+        below = node
+        while below.slot is None and below.children:
+            below = next(iter(below.children.values()))
+        if node is self.root or below.slot is None:
+            return (0, None)
+        return (node.depth, below.slot)
 
     def invalidate_slot(self, slot: int) -> None:
         """Forget every entry backed by ``slot`` and prune the now-dead
         chains: a childless node with no slot serves no lookup and would
         otherwise live in the trie (and count against ``entries``)
-        forever."""
-
-        def walk(n: _Node) -> bool:
-            """Returns True when ``n`` is prunable after the sweep."""
-            if n.slot == slot:
-                n.slot = None
-            for t in list(n.children):
-                if walk(n.children[t]):
-                    del n.children[t]
-                    self.entries -= 1
-            return not n.children and n.slot is None and n is not self.root
-
-        walk(self.root)
+        forever.  Iterative: a chain is as deep as its longest prompt."""
+        order = [(None, None, self.root)]  # (parent, token, node), pre-order
+        i = 0
+        while i < len(order):
+            node = order[i][2]
+            if node.slot == slot:
+                node.slot = None
+            order.extend((node, t, c) for t, c in node.children.items())
+            i += 1
+        for parent, t, node in reversed(order):  # children before parents
+            if parent is not None and not node.children and node.slot is None:
+                del parent.children[t]
+                self.entries -= 1
 
     def _evict(self, protect=frozenset()) -> bool:
         """Drop the oldest evictable leaf and its exclusive (childless
         once the leaf is gone, slotless) ancestor chain.  Returns False
         when nothing outside ``protect`` can be evicted."""
-
-        def oldest_leaf(n: _Node, path):
-            if not n.children:
-                stamp = n.stamp if id(n) not in protect else float("inf")
-                return (stamp, path)
-            return min((oldest_leaf(c, path + [t])
-                        for t, c in n.children.items()),
-                       key=lambda x: x[0])
-
-        stamp, path = oldest_leaf(self.root, [])
-        if not path or stamp == float("inf"):
+        up = {}  # id(node) -> (parent, token)
+        oldest, leaf = float("inf"), None
+        stack = [self.root]
+        while stack:
+            node = stack.pop()
+            for t, c in node.children.items():
+                up[id(c)] = (node, t)
+                stack.append(c)
+            if (not node.children and node is not self.root
+                    and id(node) not in protect and node.stamp < oldest):
+                oldest, leaf = node.stamp, node
+        if leaf is None:
             return False
-        # walk down recording the chain, then prune from the leaf up
-        chain = [self.root]
-        for t in path:
-            chain.append(chain[-1].children[t])
-        for i in range(len(path), 0, -1):
-            node, parent = chain[i], chain[i - 1]
+        node = leaf
+        while True:
+            parent, t = up[id(node)]
             if node.children or id(node) in protect:
                 break
-            del parent.children[path[i - 1]]
+            del parent.children[t]
             self.entries -= 1
             if parent.slot is not None or parent is self.root:
                 break
+            node = parent
         return True

@@ -195,8 +195,10 @@ def test_prefix_cache_longest_match():
     assert pc.longest_prefix([1, 2, 3, 4, 5]) == (4, 0)
     assert pc.longest_prefix([1, 2, 9, 9]) == (3, 1)
     assert pc.longest_prefix([7]) == (0, None)
+    assert pc.longest_prefix([1, 2, 5]) == (2, 0)  # diverges mid-chain
     pc.invalidate_slot(0)
-    assert pc.longest_prefix([1, 2, 3, 4, 5])[1] is None
+    # slot 0 no longer serves; slot 1 still holds the shared [1, 2]
+    assert pc.longest_prefix([1, 2, 3, 4, 5]) == (2, 1)
 
 
 # ---------------------------------------------------------------------------
