@@ -18,15 +18,25 @@ Slot-based continuous batching:
 Everything Scepsy plans (trace, aggregate, profile, schedule, place) runs
 on the host and prices engines with ``serving/costmodel.py``; the fleet-
 scale behaviour is the discrete-event simulator.
+
+Tracing: the engine's phases are ``jax.profiler.TraceAnnotation`` spans
+named ``engine.*`` (inert unless a profiler session runs), so a profile
+shows them on the device's clock beside the programs they launch; each
+role has its own jitted program (``jit_prefill``, ``jit_batched_decode``,
+``jit_suffix_decode``).  A request records when it was submitted and when
+it left the queue (``submitted_at``, ``admitted_at``).  See
+``docs/serving.md``, "Tracing the engine".
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.models.transformer import ModelBundle
 from repro.serving.kv_cache import SlotKVCache
@@ -42,6 +52,9 @@ class ServeRequest:
     slot: int = -1
     done: bool = False
     cached_tokens: int = 0  # prompt tokens served from the prefix cache
+    # time.perf_counter() at submit() and when _admit took it off the queue
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
 
 
 class ServingEngine:
@@ -68,8 +81,11 @@ class ServingEngine:
         self.stats = {"prefill_tokens": 0, "cached_tokens": 0,
                       "decode_steps": 0}
 
-        self._prefill_one = jax.jit(self._prefill_fn)
-        self._decode = jax.jit(self.bundle.decode_step)
+        # one program per role, so that a profile names what ran
+        self._prefill = _jit("prefill", lambda params, tokens:
+                             bundle.prefill(params, {"tokens": tokens}))
+        self._decode = _jit("batched_decode", bundle.decode_step)
+        self._suffix_decode = _jit("suffix_decode", bundle.decode_step)
 
     def _is_dense_kv(self, cache) -> bool:
         if not (isinstance(cache, tuple) and len(cache) == 2):
@@ -79,11 +95,8 @@ class ServingEngine:
                 and k.ndim == 5 and v.ndim == 5
                 and k.shape[1] == self.slots and k.shape[3] == self.max_len)
 
-    # -- model-facing helpers --
-    def _prefill_fn(self, params, tokens):
-        return self.bundle.prefill(params, {"tokens": tokens})
-
     def submit(self, req: ServeRequest) -> None:
+        req.submitted_at = time.perf_counter()
         self.waiting.append(req)
 
     # -- engine iterations --
@@ -103,43 +116,52 @@ class ServingEngine:
     def _admit(self) -> None:
         while self.waiting and self.free_slots:
             req = self.waiting.pop(0)
+            req.admitted_at = time.perf_counter()
             slot = self.free_slots.pop()
             req.slot = slot
             plen = len(req.prompt)
-            tokens = [int(t) for t in req.prompt]
+            with span("engine.admit", req_id=req.req_id, slot=slot,
+                      prompt_len=plen) as admit:
+                tokens = [int(t) for t in req.prompt]
+                matched, src = 0, None
+                if self.prefix_cache is not None:
+                    with span("engine.prefix_index"):
+                        matched, src = self.prefix_cache.longest_prefix(
+                            tokens)
+                        # the slot's old KV is about to be overwritten:
+                        # every cache entry still pointing at it is stale
+                        # from here on (the lookup above may legitimately
+                        # have matched it — the bytes are still in place
+                        # until we write)
+                        self.prefix_cache.invalidate_slot(slot)
+                    matched = min(matched, plen - 1)
+                    if matched < self.min_prefix:
+                        matched, src = 0, None
+                admit.set_metadata(cached=matched)
 
-            matched, src = 0, None
-            if self.prefix_cache is not None:
-                matched, src = self.prefix_cache.longest_prefix(tokens)
-                matched = min(matched, plen - 1)
-                if matched < self.min_prefix:
-                    matched, src = 0, None
-            # the slot's old KV is about to be overwritten: every cache
-            # entry still pointing at it is stale from here on (the
-            # lookup above may legitimately have matched it — the bytes
-            # are still in place until we write)
-            if self.prefix_cache is not None:
-                self.prefix_cache.invalidate_slot(slot)
-
-            if src is not None:
-                first_tok = self._prefill_from_prefix(
-                    req, slot, src, matched, tokens)
-                req.cached_tokens = matched
-                self.stats["cached_tokens"] += matched
-                self.stats["prefill_tokens"] += plen - matched
-            else:
-                logits, cache = self._prefill_one(
-                    self.params, jnp.asarray(req.prompt)[None])
-                self.stats["prefill_tokens"] += plen
-                # write the prefill cache into the slot (dense layouts)
-                self.cache = _merge_slot(self.cache, cache, slot, plen,
-                                         self.max_len)
-                first_tok = int(jnp.argmax(logits[0]))
-            self.lengths[slot] = plen
-            if self.prefix_cache is not None:
-                self.prefix_cache.insert(tokens, slot)
-            req.generated.append(first_tok)
-            self.active[slot] = req
+                if src is not None:
+                    first_tok = self._prefill_from_prefix(
+                        req, slot, src, matched, tokens)
+                    req.cached_tokens = matched
+                    self.stats["cached_tokens"] += matched
+                    self.stats["prefill_tokens"] += plen - matched
+                else:
+                    with span("engine.prefill", prompt_len=plen):
+                        logits, cache = self._prefill(
+                            self.params, jnp.asarray(req.prompt)[None])
+                        # write the prefill cache into the slot (dense
+                        # layouts)
+                        self.cache = _merge_slot(self.cache, cache, slot,
+                                                 plen, self.max_len)
+                    self.stats["prefill_tokens"] += plen
+                    with span("engine.sync"):
+                        first_tok = int(jnp.argmax(logits[0]))
+                self.lengths[slot] = plen
+                if self.prefix_cache is not None:
+                    with span("engine.prefix_index"):
+                        self.prefix_cache.insert(tokens, slot)
+                req.generated.append(first_tok)
+                self.active[slot] = req
 
     def _prefill_from_prefix(self, req: ServeRequest, slot: int, src: int,
                              matched: int, tokens: List[int]) -> int:
@@ -147,54 +169,75 @@ class ServingEngine:
         only the suffix through the model (token-at-a-time decode on an
         isolated batch=1 view of the slot), returning the first sampled
         token."""
-        kv = SlotKVCache(k=self.cache[0], v=self.cache[1],
-                         lengths=self.lengths)
-        kv.copy_prefix(src, slot, matched)
-        cache = (kv.k, kv.v)
-        k1 = jax.lax.dynamic_slice_in_dim(cache[0], slot, 1, axis=1)
-        v1 = jax.lax.dynamic_slice_in_dim(cache[1], slot, 1, axis=1)
+        with span("engine.prefix_copy", matched=matched):
+            kv = SlotKVCache(k=self.cache[0], v=self.cache[1],
+                             lengths=self.lengths)
+            kv.copy_prefix(src, slot, matched)
+            cache = (kv.k, kv.v)
+            k1 = jax.lax.dynamic_slice_in_dim(cache[0], slot, 1, axis=1)
+            v1 = jax.lax.dynamic_slice_in_dim(cache[1], slot, 1, axis=1)
         logits = None
-        for pos in range(matched, len(tokens)):
-            logits, (k1, v1) = self._decode(
-                self.params, (k1, v1),
-                jnp.asarray([tokens[pos]], jnp.int32),
-                jnp.asarray([pos], jnp.int32))
-        self.cache = (
-            jax.lax.dynamic_update_slice(cache[0], k1, (0, slot, 0, 0, 0)),
-            jax.lax.dynamic_update_slice(cache[1], v1, (0, slot, 0, 0, 0)))
-        return int(jnp.argmax(logits[0]))
+        with span("engine.suffix", tokens=len(tokens) - matched):
+            for pos in range(matched, len(tokens)):
+                logits, (k1, v1) = self._suffix_decode(
+                    self.params, (k1, v1),
+                    jnp.asarray([tokens[pos]], jnp.int32),
+                    jnp.asarray([pos], jnp.int32))
+            self.cache = (
+                jax.lax.dynamic_update_slice(cache[0], k1,
+                                             (0, slot, 0, 0, 0)),
+                jax.lax.dynamic_update_slice(cache[1], v1,
+                                             (0, slot, 0, 0, 0)))
+        with span("engine.sync"):
+            return int(jnp.argmax(logits[0]))
 
     def _decode_step(self) -> List[ServeRequest]:
         if not self.active:
             return []
         slots = sorted(self.active)
-        tokens = np.zeros(self.slots, np.int32)
-        for s in slots:
-            tokens[s] = self.active[s].generated[-1]
-        pos = jnp.asarray(self.lengths, jnp.int32)
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(tokens), pos)
-        self.stats["decode_steps"] += 1
-        completed = []
-        toks = np.asarray(jnp.argmax(logits, axis=-1))
-        for s in slots:
-            req = self.active[s]
-            self.lengths[s] += 1
-            req.generated.append(int(toks[s]))
-            if (len(req.generated) >= req.max_new_tokens
-                    or self.lengths[s] >= self.max_len - 1):
-                req.done = True
-                completed.append(req)
-                del self.active[s]
-                # the slot's KV (prompt + all but the final generated
-                # token) stays valid until the slot is reused; register
-                # the full sequence for exact-prefix reuse
-                if self.prefix_cache is not None:
-                    seq = [int(t) for t in req.prompt] + req.generated[:-1]
-                    self.prefix_cache.insert(seq[:self.max_len - 1], s)
-                self.lengths[s] = 0
-                self.free_slots.append(s)
+        with span("engine.decode", active=len(slots)):
+            with span("engine.dispatch"):
+                tokens = np.zeros(self.slots, np.int32)
+                for s in slots:
+                    tokens[s] = self.active[s].generated[-1]
+                pos = jnp.asarray(self.lengths, jnp.int32)
+                logits, self.cache = self._decode(
+                    self.params, self.cache, jnp.asarray(tokens), pos)
+            self.stats["decode_steps"] += 1
+            with span("engine.sync"):
+                toks = np.asarray(jnp.argmax(logits, axis=-1))
+            with span("engine.bookkeep"):
+                completed = []
+                for s in slots:
+                    req = self.active[s]
+                    self.lengths[s] += 1
+                    req.generated.append(int(toks[s]))
+                    if (len(req.generated) >= req.max_new_tokens
+                            or self.lengths[s] >= self.max_len - 1):
+                        req.done = True
+                        completed.append(req)
+                        del self.active[s]
+                        # the slot's KV (prompt + all but the final
+                        # generated token) stays valid until the slot is
+                        # reused; register the full sequence for
+                        # exact-prefix reuse
+                        if self.prefix_cache is not None:
+                            seq = ([int(t) for t in req.prompt]
+                                   + req.generated[:-1])
+                            with span("engine.prefix_index"):
+                                self.prefix_cache.insert(
+                                    seq[:self.max_len - 1], s)
+                        self.lengths[s] = 0
+                        self.free_slots.append(s)
         return completed
+
+
+def _jit(name: str, fn):
+    """``fn`` jitted as a program the profiler names ``jit_<name>``."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program)
 
 
 def _merge_slot(cache, prefill_cache, slot: int, plen: int, max_len: int):
